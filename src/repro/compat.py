@@ -1,62 +1,40 @@
-"""Version-tolerance shims for the JAX API surface this repo uses.
+"""The JAX API points this repo routes through one module.
 
-The repo targets a range of JAX releases (CI pins one, clusters run
-others) and three API points have drifted across that range:
+The repo is written for jax/jaxlib 0.9.0 (``requirements.txt`` pins
+it). What lives here:
 
-* ``jax.make_mesh`` grew an ``axis_types`` kwarg (and the
-  ``jax.sharding.AxisType`` enum) in 0.5.x; earlier releases have
-  neither.
-* ``shard_map`` moved from ``jax.experimental.shard_map`` (kwarg
-  ``check_rep``) to ``jax.shard_map`` (kwarg ``check_vma``).
-* replication/vma checking must be off either way: ``pallas_call``
-  inside ``shard_map`` can't declare vma on its ``out_shape``
+* mesh construction with every axis declared ``AxisType.Auto`` (the
+  repo-wide convention: shardings are explicit NamedShardings +
+  shard_map, never inferred Explicit-mode axes);
+* ``shard_map`` with vma checking off: ``pallas_call`` inside
+  ``shard_map`` can't declare vma on its ``out_shape``
   ShapeDtypeStructs — the escape hatch the error message itself
-  recommends.
-* multi-process bring-up drifts twice over: the CPU backend needs its
-  collectives implementation switched to ``gloo`` (a config knob whose
-  name/presence varies), and ``jax.distributed.initialize`` has grown
-  and renamed kwargs across releases.
+  recommends;
+* multi-process bring-up: the CPU backend needs its collectives
+  implementation switched to ``gloo`` before it initializes;
+* the device-placement queries (which mesh axes cross processes) that
+  every layer from the FFT schedule engine up needs.
 
 All mesh construction, every ``shard_map``, and the cluster bootstrap
-(``repro.runtime.cluster``) route through here; nothing else should
-touch those APIs directly.
+(``repro.runtime.cluster``) route through here.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
 
 
-def jax_version() -> Tuple[int, ...]:
-    parts = []
-    for p in jax.__version__.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
+def _auto_axes(axis_names: Sequence[str]):
+    return (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None):
-    """``jax.make_mesh`` that omits ``axis_types`` on JAX < 0.5.
-
-    When the running JAX has ``jax.sharding.AxisType`` every axis is
-    declared ``Auto`` (the repo-wide convention: shardings are explicit
-    NamedShardings + shard_map, never inferred Explicit-mode axes);
-    older releases have only Auto semantics, so omitting the kwarg is
-    behavior-identical.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _HAS_AXIS_TYPE:
-        kwargs["axis_types"] = (
-            jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=_auto_axes(axis_names), **kwargs)
 
 
 def make_explicit_mesh(devices, axis_names: Sequence[str]):
@@ -64,33 +42,17 @@ def make_explicit_mesh(devices, axis_names: Sequence[str]):
 
     ``jax.make_mesh`` may permute devices for collective efficiency,
     which would silently destroy a process-major DCN×ICI layout; the
-    raw ``Mesh`` constructor honors placement verbatim. Axis types are
-    declared ``Auto`` when the running JAX has them (same convention
-    as ``make_mesh`` above).
+    raw ``Mesh`` constructor honors placement verbatim. Axes are
+    ``Auto``, as in ``make_mesh``.
     """
-    kwargs = {}
-    if _HAS_AXIS_TYPE:
-        kwargs["axis_types"] = (
-            jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
-    return jax.sharding.Mesh(devices, tuple(axis_names), **kwargs)
-
-
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # noqa: N813
-    params = inspect.signature(fn).parameters
-    check_kw = "check_vma" if "check_vma" in params else "check_rep"
-    return fn, check_kw
-
-
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
+    return jax.sharding.Mesh(devices, tuple(axis_names),
+                             axis_types=_auto_axes(axis_names))
 
 
 def shard_map(body, *, mesh, in_specs, out_specs):
-    """Version-dispatched ``shard_map`` with rep/vma checking disabled."""
-    return _SHARD_MAP(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: False})
+    """``jax.shard_map`` with vma checking disabled."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def axis_crosses_processes(mesh, axis_name: str) -> bool:
@@ -136,17 +98,9 @@ def backend_initialized() -> bool:
     that point, bring-up configuration (the gloo collectives selector,
     ``jax.distributed.initialize``) silently stops taking effect, so
     cluster init must detect it explicitly (``jax.config.update`` still
-    *succeeds* on an initialized backend). Private-API probe with
-    graceful degradation: unknown layouts report False rather than
-    blocking bring-up."""
-    try:
-        from jax._src import xla_bridge
-        fn = getattr(xla_bridge, "backends_are_initialized", None)
-        if fn is not None:
-            return bool(fn())
-        return bool(getattr(xla_bridge, "_backends", None))
-    except Exception:  # noqa: BLE001 — layout drift: assume fresh
-        return False
+    *succeeds* on an initialized backend)."""
+    from jax._src import xla_bridge
+    return bool(xla_bridge.backends_are_initialized())
 
 
 def enable_cpu_collectives() -> bool:
@@ -155,10 +109,9 @@ def enable_cpu_collectives() -> bool:
     Multi-process CPU clusters fail at the first collective with
     "Multiprocess computations aren't implemented on the CPU backend"
     unless the gloo implementation is selected BEFORE the backend
-    initializes. The config knob exists on the JAX range this repo
-    targets but not on every release — returns False (rather than
-    raising) when it is absent or the backend is already up, so callers
-    can surface a clear bring-up error instead of the XLA one.
+    initializes. Returns False (rather than raising) when the update
+    is refused, so callers can surface a clear bring-up error instead
+    of the XLA one.
     """
     try:
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
@@ -167,41 +120,10 @@ def enable_cpu_collectives() -> bool:
         return False
 
 
-def distributed_initialize(coordinator_address: str, num_processes: int,
-                           process_id: int) -> None:
-    """``jax.distributed.initialize`` across its signature drift.
-
-    Newer releases accept (and sometimes require) extra kwargs; the
-    three positional-capable basics have been stable, so pass exactly
-    those and let each release fill in its own defaults.
-    """
-    jax.distributed.initialize(coordinator_address=coordinator_address,
-                               num_processes=num_processes,
-                               process_id=process_id)
-
-
 def distributed_shutdown() -> None:
-    """Best-effort ``jax.distributed.shutdown`` (absent on old JAX)."""
-    fn = getattr(jax.distributed, "shutdown", None)
-    if fn is not None:
-        try:
-            fn()
-        except RuntimeError:
-            pass                      # never initialized / already down
-
-
-def set_mesh(mesh):
-    """Context manager making ``mesh`` the ambient default mesh.
-
-    ``jax.set_mesh`` (new releases) / ``jax.sharding.use_mesh``
-    (transition releases) / the legacy ``with mesh:`` resource-env
-    context (0.4.x, where ``Mesh`` itself is the context manager).
-    The repo pins every sharding explicitly (NamedSharding +
-    shard_map), so the three are behavior-identical here.
-    """
-    fn = getattr(jax, "set_mesh", None)
-    if fn is None:
-        fn = getattr(jax.sharding, "use_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
+    """``jax.distributed.shutdown``; a no-op when never initialized or
+    already down."""
+    try:
+        jax.distributed.shutdown()
+    except RuntimeError:
+        pass

@@ -42,8 +42,11 @@ def to_complex(p: Pair):
 # ---------------------------------------------------------------------------
 
 def dft_matrix(n: int, sign: float) -> Pair:
-    k = jnp.arange(n, dtype=jnp.float32)
-    ang = sign * 2.0 * math.pi * jnp.outer(k, k) / n
+    # reduce j·k mod n in integers first: the float32 product j·k/n
+    # reaches ~n radians and would lose ~n·2⁻²⁴ of angle per entry
+    k = jnp.arange(n, dtype=jnp.int32)
+    jk = (jnp.outer(k, k) % n).astype(jnp.float32)
+    ang = sign * 2.0 * math.pi * jk / n
     return jnp.cos(ang), jnp.sin(ang)
 
 
@@ -60,12 +63,11 @@ def cmul(ar, ai, br, bi) -> Pair:
 
 
 def cmatmul(ar, ai, br, bi) -> Pair:
-    """(...,m,k) complex @ (k,n) complex via four real matmuls."""
-    rr = ar @ br
-    ii = ai @ bi
-    ri = ar @ bi
-    ir = ai @ br
-    return rr - ii, ri + ir
+    """(...,m,k) complex @ (k,n) complex via four real matmuls, at
+    ``Precision.HIGHEST``: a TPU's default precision is one bf16 pass,
+    which misses float32 FFT accuracy."""
+    dot = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    return dot(ar, br) - dot(ai, bi), dot(ar, bi) + dot(ai, br)
 
 
 # ---------------------------------------------------------------------------

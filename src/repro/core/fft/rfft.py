@@ -136,11 +136,11 @@ def rfft_slab_schedule(n1: int, mesh: Mesh, axis_name: str = "data", *,
     if inverse:
         stages = (LocalFFT(-2, True, backend),
                   AllToAll(axis_name, -2, -1, pn, w),
-                  LocalIRFFT(n1, half_bins(n1)))
+                  LocalIRFFT(n1, half_bins(n1), backend))
         return Schedule("rfft_slab_inv", 2, stages,
                         (None, axis_name), (axis_name, None),
                         in_arity=2, out_arity=1)
-    stages = (LocalRFFT(hp),
+    stages = (LocalRFFT(hp, backend),
               AllToAll(axis_name, -1, -2, pn, w),
               LocalFFT(-2, False, backend))
     return Schedule("rfft_slab", 2, stages,
@@ -163,11 +163,11 @@ def rfft_pencil_schedule(n2: int, mesh: Mesh,
                   AllToAll(a0, -3, -2, p0, wa),
                   LocalFFT(-2, True, backend),
                   AllToAll(a1, -2, -1, p1, wb),
-                  LocalIRFFT(n2, half_bins(n2)))
+                  LocalIRFFT(n2, half_bins(n2), backend))
         return Schedule("rfft_pencil_inv", 3, stages,
                         (None, a0, a1), (a0, a1, None),
                         in_arity=2, out_arity=1)
-    stages = (LocalRFFT(hp),
+    stages = (LocalRFFT(hp, backend),
               AllToAll(a1, -1, -2, p1, wa),
               LocalFFT(-2, False, backend),
               AllToAll(a0, -2, -3, p0, wb),
@@ -192,11 +192,11 @@ def rfft_slab3d_schedule(n2: int, mesh: Mesh, axis_name: str = "data", *,
         stages = (LocalFFT(-3, True, backend),
                   AllToAll(axis_name, -3, -2, pn, w),
                   LocalFFT(-2, True, backend),
-                  LocalIRFFT(n2, h))
+                  LocalIRFFT(n2, h, backend))
         return Schedule("rfft_slab3d_inv", 3, stages,
                         (None, axis_name, None), (axis_name, None, None),
                         in_arity=2, out_arity=1)
-    stages = (LocalRFFT(h),
+    stages = (LocalRFFT(h, backend),
               LocalFFT(-2, False, backend),
               AllToAll(axis_name, -2, -3, pn, w),
               LocalFFT(-3, False, backend))
@@ -232,11 +232,11 @@ def rfft_pencil_tf_schedule(n2: int, mesh: Mesh,
                   LocalFFT(-3, True, backend),        # x local
                   LocalFFT(-2, True, backend),        # y
                   AllToAll(a1, -2, -1, p1, wb),       # y ↔ z rotation
-                  LocalIRFFT(n2, half_bins(n2)))
+                  LocalIRFFT(n2, half_bins(n2), backend))
         return Schedule("rfft_pencil_tf_inv", 3, stages,
                         (a0, None, a1), (a0, a1, None),
                         in_arity=2, out_arity=1)
-    stages = (LocalRFFT(hp),                          # z (half-spectrum)
+    stages = (LocalRFFT(hp, backend),                 # z (half-spectrum)
               AllToAll(a1, -1, -2, p1, wa),           # z ↔ y rotation
               LocalFFT(-2, False, backend),           # y
               LocalFFT(-3, False, backend),           # x local (cyclic)
@@ -269,13 +269,13 @@ def rfft_pencil2d_schedule(n1: int, mesh: Mesh,
         stages = (LocalFFT(-2, True, backend),
                   AllToAll(a0, -2, -1, p0, w0),       # undo k0 scatter
                   AllToAll(a1, -2, -1, p1, w1),       # regroup half axis
-                  LocalIRFFT(n1, half_bins(n1)),
+                  LocalIRFFT(n1, half_bins(n1), backend),
                   AllToAll(a1, -1, -2, p1, w2))       # re-scatter real x
         return Schedule("rfft_pencil2d_inv", 2, stages,
                         (None, (a1, a0)), (a0, a1),
                         in_arity=2, out_arity=1)
     stages = (AllToAll(a1, -2, -1, p1, w0),           # gather REAL axis 1
-              LocalRFFT(hp),
+              LocalRFFT(hp, backend),
               AllToAll(a1, -1, -2, p1, w1),           # scatter half axis
               AllToAll(a0, -1, -2, p0, w2),           # gather axis 0
               LocalFFT(-2, False, backend))

@@ -326,11 +326,10 @@ class TransitBridge:
             under default x64-disabled jax) — a silent precision loss
             that would break the bit-identical contract. Gather the
             raw bytes instead and reinterpret on arrival."""
-            a = np.ascontiguousarray(a)
-            g = np.asarray(process_allgather(a.view(np.uint8)))
-            if jax.process_count() == 1:
-                g = g[None]      # single process: no leading axis added
-            return g.view(a.dtype)
+            u8 = np.ascontiguousarray(a).view(np.uint8)
+            g = np.asarray(process_allgather(u8))
+            return g.reshape((jax.process_count(),) + u8.shape) \
+                .view(a.dtype)
 
         rows, flats, seen = [], [], set()
         if isinstance(x, jax.Array):

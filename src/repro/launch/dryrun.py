@@ -25,7 +25,6 @@ from pathlib import Path
 
 import jax
 
-from repro import compat
 from repro.configs import registry
 from repro.configs.base import SHAPES
 from repro.launch import specs as specs_mod
@@ -51,7 +50,7 @@ def lower_cell(cfg, shape, mesh, *, multi_pod: bool, **overrides):
     jitted = jax.jit(built["fn"], in_shardings=built["in_shardings"],
                      out_shardings=built.get("out_shardings"),
                      donate_argnums=built["donate_argnums"])
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*built["args"])
         compiled = lowered.compile()
     return built, compiled
@@ -163,4 +162,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

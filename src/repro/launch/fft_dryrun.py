@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 from repro.core.fft import distributed as D
 from repro.core.fft.filters import lowpass_mask
@@ -131,7 +130,7 @@ def run_cell(kind: str, mesh_name: str = "pod1") -> dict:
               "chips": chips, "status": "ok"}
     try:
         fn, args, in_sh, mf = build(kind, mesh)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
             compiled = lowered.compile()
         result["memory"] = rl.memory_report(compiled)
@@ -190,4 +189,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

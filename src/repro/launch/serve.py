@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import registry
 from repro.core.fft import plan as plan_mod
 from repro.launch.mesh import make_host_mesh
@@ -247,7 +246,7 @@ def main(argv=None):
               if monitor is not None else None)
     snapshots = 0
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.perf_counter()
         logits, state = prefill(params, {"tokens": prompts})
         logits.block_until_ready()
@@ -332,4 +331,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

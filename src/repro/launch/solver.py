@@ -20,12 +20,13 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.compat import make_mesh
 from repro.core.fft import plan as plan_mod
 from repro.core.insitu.bridge import BridgeData, GridMeta
 from repro.core.insitu.chain import InSituChain
 from repro.core.insitu.endpoints.writer import WriterEndpoint
 from repro.core.solver import Boussinesq3DSolver, NS2DSolver
-from repro.launch.mesh import make_host_mesh, make_multihost_mesh
+from repro.launch.mesh import make_multihost_mesh
 from repro.runtime.cluster import (add_cluster_args, config_from_args,
                                    init_cluster)
 
@@ -81,9 +82,10 @@ def main(argv=None):
                          "(taylor-green for ns2d, beltrami for bq3d)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--mesh-shape", type=int, nargs="+", default=None,
-                    help="single-process mesh shape over host devices, "
-                         "e.g. --mesh-shape 4 2 (default: all devices "
-                         "on one axis)")
+                    help="single-process mesh shape, e.g. --mesh-shape "
+                         "4 2, over the first devices; exits when they "
+                         "do not fit (default: all devices on one "
+                         "axis)")
     ap.add_argument("--monitor-every", type=int, default=5)
     ap.add_argument("--spectrum-bins", type=int, default=16)
     ap.add_argument("--spectra-dir", default=None,
@@ -158,10 +160,17 @@ def main(argv=None):
     elif jax.process_count() > 1:
         mesh = make_multihost_mesh()
     else:
+        devices = jax.devices()
         shape = (tuple(args.mesh_shape) if args.mesh_shape
-                 else (len(jax.devices()),))
-        names = ("data", "model")[: len(shape)]
-        mesh = make_host_mesh(shape, names)
+                 else (len(devices),))
+        need = int(np.prod(shape))
+        if len(shape) > 2 or need > len(devices):
+            raise SystemExit(
+                f"--mesh-shape {' '.join(map(str, shape))} needs "
+                f"{need} devices on at most 2 axes; this process has "
+                f"{len(devices)} {devices[0].platform} device(s)")
+        mesh = make_mesh(shape, ("data", "model")[: len(shape)],
+                         devices=devices[:need])
 
     t0 = time.perf_counter()
     solver = build_solver(args, mesh)
@@ -191,6 +200,7 @@ def main(argv=None):
     done = 0
     while done < args.steps:
         n = min(args.monitor_every, args.steps - done)
+        t_interval = time.perf_counter()
         solver.step(n)
         done += n
         rep = {"step": solver.step_count, "t": round(solver.t, 6),
@@ -199,6 +209,9 @@ def main(argv=None):
             rep["enstrophy"] = solver.enstrophy()
         else:
             rep["scalar_variance"] = solver.scalar_variance()
+        # the diagnostics above copy the state to the host, so this
+        # interval ends after the device finished its steps
+        rep["interval_s"] = time.perf_counter() - t_interval
         reports.append(rep)
         if jax.process_index() == 0:
             print(json.dumps(rep))
@@ -259,6 +272,7 @@ def main(argv=None):
         "steps_per_s": round(args.steps / max(wall, 1e-9), 3),
         "bringup_s": round(bringup_s, 4),
         "final": reports[-1] if reports else None,
+        "reports": reports,
         "spectra_files": len(files),
         "plan_stats": {"wisdom_hits": stats1["wisdom_hits"],
                        "sweep_candidates_timed":
@@ -274,4 +288,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

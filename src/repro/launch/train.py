@@ -21,7 +21,6 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 from repro.configs import registry
 from repro.core.fft import plan as plan_mod
@@ -263,7 +262,7 @@ def main(argv=None):
                   flush=True)
 
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state, report = run_with_restarts(
             make_state=make_state, train_step=step_fn, batch_fn=batch_fn,
             total_steps=args.steps, ckpt_dir=args.ckpt_dir,
@@ -295,4 +294,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -12,10 +12,10 @@ process bring-up. This module owns exactly that:
   ``add_cluster_args``/``config_from_args`` expose the same knobs as
   CLI flags for schedulers that prefer argv over env.
 * **Initialization** — ``init_cluster()`` is idempotent, a no-op for
-  single-process runs, and routes every drifting JAX API through
-  ``repro.compat`` (gloo CPU collectives, ``distributed.initialize``
-  signature drift). It must run BEFORE the first JAX backend use; on
-  CPU the per-process device count additionally needs
+  single-process runs, and selects gloo CPU collectives through
+  ``repro.compat`` before ``jax.distributed.initialize``. It must run
+  BEFORE the first JAX backend use; on CPU the per-process device
+  count additionally needs
   ``XLA_FLAGS=--xla_force_host_platform_device_count=K`` set before
   the first ``import jax`` (the launcher does both).
 * **Topology queries** — ``axis_crosses_processes(mesh, axis)`` is the
@@ -181,7 +181,7 @@ def init_cluster(config: Optional[ClusterConfig] = None) -> ClusterConfig:
         primary = (os.environ.get("JAX_PLATFORMS", "")
                    .split(",")[0].strip().lower())
         if not compat.enable_cpu_collectives() and primary in ("", "cpu"):
-            # the knob is absent (old JAX) — surface the clear
+            # the gloo selector was refused — surface the clear
             # bring-up error the compat shim promises instead of XLA's
             # cryptic first-collective failure (the launcher maps this
             # to its "unsupported environment" exit, so tests SKIP)
@@ -193,8 +193,9 @@ def init_cluster(config: Optional[ClusterConfig] = None) -> ClusterConfig:
                 "aren't implemented on the CPU backend\". (On an "
                 "accelerator cluster, set JAX_PLATFORMS to your "
                 "platform to bypass this CPU-only check.)")
-        compat.distributed_initialize(cfg.coordinator, cfg.num_processes,
-                                      cfg.process_id)
+        jax.distributed.initialize(coordinator_address=cfg.coordinator,
+                                   num_processes=cfg.num_processes,
+                                   process_id=cfg.process_id)
     _STATE["initialized"] = True
     _STATE["config"] = cfg
     return cfg

@@ -68,7 +68,8 @@ def test_fft_kernel_property_roundtrip(b, n, seed):
     assert float(jnp.max(jnp.abs(bi - im))) < 1e-3
 
 
-@pytest.mark.parametrize("shape", [(64, 64), (256, 200), (128, 1000)])
+@pytest.mark.parametrize("shape", [(64, 64), (256, 200), (128, 1000),
+                                   (512, 4097), (100, 4097)])
 def test_bandpass_kernel(shape):
     R, C = shape
     re = jnp.asarray(RNG.standard_normal((R, C)).astype(np.float32))
@@ -80,6 +81,27 @@ def test_bandpass_kernel(shape):
     np.testing.assert_allclose(np.asarray(outi), np.asarray(ri))
     np.testing.assert_allclose(float(kept), float(rk), rtol=1e-5)
     np.testing.assert_allclose(float(tot), float(rt), rtol=1e-5)
+
+
+@pytest.mark.parametrize("kind,shape", [
+    ("bandpass", (8192, 4097)), ("bandpass", (200, 200)),
+    ("bandpass", (104, 4097)), ("fft", (1024, 4096)), ("fft", (1024, 256)),
+    ("fft", (7, 360))])
+def test_kernel_blocks_fit_vmem(kind, shape):
+    """Every in/out block, double-buffered, fits the VMEM block budget,
+    and a split row axis is cut in multiples of 8 (Mosaic's tiling)."""
+    R, C = shape
+    if kind == "bandpass":
+        br = ops.bandpass_block_rows(R, C)
+        assert R % br == 0 and (br == R or br % 8 == 0)
+        used = 10 * ops._tile_bytes(br, C)
+    else:
+        from repro.core.fft.dft import split_factor
+        n1, n2 = split_factor(C)
+        bb = ops.fft_block_b(R, C)
+        assert R % bb == 0
+        used = 8 * bb * ops._tile_bytes(n2, n1)
+    assert used <= ops.VMEM_BLOCK_BUDGET
 
 
 def test_pallas_backend_in_fft_core():
