@@ -1,0 +1,1 @@
+"""The chip benchmark: one cell per run, driven by ``BENCHMARK.json``."""

@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device,
+averaged over the chips (1 − busy / window)."""
+from bench.metrics._device import idle_pct
+
+
+def read(run):
+    return idle_pct(run, "step")
