@@ -416,15 +416,24 @@ class FFTPlan:
         the signal behind the ``decomp="measure"`` sweep."""
         return exchange_topology(self.schedule())
 
+    def scope(self) -> str:
+        """The plan's ``named_scope`` in traces: ``plan.r2c``,
+        ``plan.c2r``, ``plan.fwd`` or ``plan.bwd``."""
+        forward = self.direction == FORWARD
+        if self.real:
+            return "plan.r2c" if forward else "plan.c2r"
+        return "plan.fwd" if forward else "plan.bwd"
+
     def compile(self) -> "FFTPlan":
         sched = self.schedule()
         if self.overlap_chunks and self.overlap_chunks > 1:
             overlap_site(sched)       # raise a clear error at plan time
-        mesh, chunks = self.mesh, self.overlap_chunks
+        mesh, chunks, scope = self.mesh, self.overlap_chunks, self.scope()
 
         def fn(*arrays):
-            return execute_schedule(sched, mesh, *arrays,
-                                    overlap_chunks=chunks)
+            with jax.named_scope(scope):
+                return execute_schedule(sched, mesh, *arrays,
+                                        overlap_chunks=chunks)
 
         self._fn = jax.jit(fn)
         return self

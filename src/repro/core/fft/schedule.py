@@ -400,6 +400,13 @@ def overlap_site(sched: Schedule) -> Tuple[int, int]:
     return k, t
 
 
+def _apply(i: int, stage, state):
+    """Stage ``i`` of a schedule under its ``named_scope`` in traces,
+    ``<i>.<StageClass>`` (``0.LocalRFFT``, ``1.AllToAll``, ...)."""
+    with jax.named_scope(f"{i}.{type(stage).__name__}"):
+        return stage.apply(state)
+
+
 def _run_overlap(sched: Schedule, state, k: int, t: int, chunks: int):
     """Chunked pipeline: stages[:k+1] per chunk along axis t, then
     un-interleave and run the rest. The unchunked all_to_all orders the
@@ -418,8 +425,8 @@ def _run_overlap(sched: Schedule, state, k: int, t: int, chunks: int):
     for j in range(chunks):
         sub = tuple(jax.lax.slice_in_dim(x, j * c, (j + 1) * c, axis=tpos)
                     for x in state)
-        for st in sched.stages[: k + 1]:
-            sub = st.apply(sub)
+        for i, st in enumerate(sched.stages[: k + 1]):
+            sub = _apply(i, st, sub)
         parts.append(sub)
     arity = len(parts[0])
     state = tuple(jnp.concatenate([p[i] for p in parts], axis=t)
@@ -435,8 +442,8 @@ def _run_overlap(sched: Schedule, state, k: int, t: int, chunks: int):
         return y.reshape(shp)
 
     state = tuple(fix(x) for x in state)
-    for st in sched.stages[k + 1:]:
-        state = st.apply(state)
+    for i, st in enumerate(sched.stages[k + 1:], start=k + 1):
+        state = _apply(i, st, state)
     return state
 
 
@@ -464,8 +471,8 @@ def execute_schedule(sched: Schedule, mesh: Mesh, *arrays,
         if site is not None:
             state = _run_overlap(sched, state, site[0], site[1], chunks)
         else:
-            for st in sched.stages:
-                state = st.apply(state)
+            for i, st in enumerate(sched.stages):
+                state = _apply(i, st, state)
         return state if len(state) > 1 else state[0]
 
     in_specs = (in_spec,) * sched.in_arity

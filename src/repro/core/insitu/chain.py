@@ -36,9 +36,16 @@ import jax
 
 from repro.core.insitu.bridge import BridgeData
 from repro.core.insitu.endpoint import Endpoint
-from repro.core.insitu.pipeline import HostPipeline, overlap_stats
+from repro.core.insitu.pipeline import HostPipeline, overlap_stats, span_step
 
 MODES = ("insitu", "intransit", "pipelined")
+
+
+def _scoped(idx: int, ep: Endpoint, d: BridgeData) -> BridgeData:
+    """Endpoint ``idx`` of the chain under its ``named_scope`` in
+    traces, ``insitu.<idx>.<name>`` (``insitu.0.fft``, ...)."""
+    with jax.named_scope(f"insitu.{idx}.{ep.name}"):
+        return ep.execute(d)
 
 
 class InSituChain:
@@ -175,8 +182,8 @@ class InSituChain:
         device_eps = self._device_prefix()
 
         def run(d: BridgeData) -> BridgeData:
-            for ep in device_eps:
-                d = ep.execute(d)
+            for idx, ep in enumerate(device_eps):
+                d = _scoped(idx, ep, d)
             return d
         return jax.jit(run, donate_argnums=(0,) if donate else ())
 
@@ -236,8 +243,10 @@ class InSituChain:
             # ONE field, not the backlog
             jax.block_until_ready(jax.tree.leaves(self._probe_prev))
             self._probe_prev = None
+        step = span_step(data.step)
         t0 = time.perf_counter()
-        out = self._pipe_fn(data) if device_eps else data
+        with jax.profiler.TraceAnnotation("insitu.dispatch", step=step):
+            out = self._pipe_fn(data) if device_eps else data
         # async dispatch: this measures LAUNCH cost, not device compute
         self._dispatch_s += time.perf_counter() - t0
         if probing:
@@ -254,7 +263,7 @@ class InSituChain:
             self._probe_prev = jax.tree.leaves(out.arrays)
         self._pipe_calls += 1
         if self._pipeline is not None:
-            self._pipeline.submit(out)          # backpressure lives here
+            self._pipeline.submit(out, step=step)   # backpressure here
         return out
 
     def _staged_fn(self, idx: int, ep: Endpoint):
@@ -264,7 +273,8 @@ class InSituChain:
         re-trace/compile on every chain execution."""
         fn = self._staged_fns.get(idx)
         if fn is None:
-            fn = self._staged_fns[idx] = jax.jit(ep.execute)
+            fn = self._staged_fns[idx] = jax.jit(
+                lambda d: _scoped(idx, ep, d))
         return fn
 
     def _execute_staged(self, data: BridgeData) -> BridgeData:
@@ -345,8 +355,8 @@ class InSituChain:
 
         def hook(payload: Dict[str, Any]) -> Dict[str, Any]:
             d = BridgeData(arrays=dict(payload), domain="spatial")
-            for ep in device_eps:
-                d = ep.execute(d)
+            for idx, ep in enumerate(device_eps):
+                d = _scoped(idx, ep, d)
             return {k: v for k, v in d.arrays.items()
                     if k.startswith("insitu_")}
         return hook

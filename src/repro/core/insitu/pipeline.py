@@ -20,9 +20,11 @@ gaps; this module is the host half of that mode:
   instead of buffering unboundedly (each queued field pins its device
   output alive).
 * Everything is accounted: per-endpoint host timings, materialization
-  wait, backpressure stalls, queue-depth stats, and completed/dropped
-  field counts feed ``chain.marshaling_report()``'s overlap-efficiency
-  numbers.
+  wait (and within it the copies' time and bytes), backpressure
+  stalls, queue-depth stats, and completed/dropped field counts feed
+  ``chain.marshaling_report()``'s overlap-efficiency numbers; in a
+  profiler trace the same steps are host spans
+  (``docs/architecture.md``, "Tracing").
 
 Ordering and failure semantics:
 
@@ -41,17 +43,20 @@ See ``docs/architecture.md`` (mode diagrams) and ``docs/endpoints.md``
 """
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
+import numpy as np
 
 from repro.core.insitu.bridge import BridgeData
 from repro.core.insitu.endpoint import Endpoint
 
 _STOP = object()
+_SECS = ("wait_s", "d2h_s", "backpressure_s")   # HostPipeline's counters
 
 
 class PipelineError(RuntimeError):
@@ -106,9 +111,12 @@ class HostPipeline:
         self._submitted = 0
         self._done = 0
         self._dropped = 0
-        self._wait_s = 0.0            # blocked materializing device results
+        # wait_s: blocked materializing device results; d2h_s: the
+        # copies alone, within wait_s; backpressure_s: producer blocked
+        # on the full queue
+        self._secs = dict.fromkeys(_SECS, 0.0)
+        self._d2h_bytes = 0           # what the copies landed on the host
         self._host_s: Dict[str, float] = {}   # per-endpoint busy time
-        self._backpressure_s = 0.0    # producer blocked on the full queue
         self._depth_max = 0
         self._depth_sum = 0
         self._last_out: Optional[BridgeData] = None
@@ -120,20 +128,23 @@ class HostPipeline:
             t.start()
 
     # -- producer side ---------------------------------------------------------
-    def submit(self, data: BridgeData) -> None:
+    def submit(self, data: BridgeData, *, step=None) -> None:
         """Enqueue one field's device output for host processing.
 
         Blocks while ``depth`` fields are in flight (backpressure).
-        Raises the stored :class:`PipelineError` if a previous field
-        failed, and ``RuntimeError`` after ``close``.
+        ``step`` labels the field's spans in a profiler trace (default
+        ``data.step``; see :func:`span_step`). Raises the stored
+        :class:`PipelineError` if a previous field failed, and
+        ``RuntimeError`` after ``close``.
         """
         if self._error is not None:
             raise self._error
         if self._closed:
             raise RuntimeError("pipeline is closed; re-initialize the chain")
-        t0 = time.perf_counter()
-        self._q.put(data)
-        self._backpressure_s += time.perf_counter() - t0
+        step = span_step(data.step if step is None else step)
+        with timed_span("insitu.submit", step, self._lock, self._secs,
+                        "backpressure_s"):
+            self._q.put((data, step))
         with self._lock:
             self._submitted += 1
             d = self._q.qsize()
@@ -176,28 +187,34 @@ class HostPipeline:
                     with self._lock:
                         self._dropped += 1
                     continue
-                self._run_one(item)
+                self._run_one(*item)
             finally:
                 self._q.task_done()
 
-    def _run_one(self, data: BridgeData) -> None:
+    def _run_one(self, data: BridgeData, step: int) -> None:
         ep_name = "<device_get>"
+        lock, secs = self._lock, self._secs
         try:
             # Materialize the in-flight device results HERE, off the
-            # producer's critical path: device_get blocks on the XLA
-            # program and lands host copies every endpoint can share
-            # (each np.asarray afterwards is free).
-            t0 = time.perf_counter()
-            data = data.replace(arrays=jax.device_get(data.arrays))
-            with self._lock:
-                self._wait_s += time.perf_counter() - t0
+            # producer's critical path: wait for the XLA program, then
+            # land host copies every endpoint can share (each
+            # np.asarray afterwards is free).
+            with timed_span("insitu.wait_device", step, lock, secs,
+                            "wait_s"):
+                jax.block_until_ready(data.arrays)
+            with timed_span("insitu.d2h", step, lock, secs,
+                            "wait_s", "d2h_s") as d2h:
+                data = data.replace(arrays=jax.device_get(data.arrays))
+                nbytes = sum(np.asarray(x).nbytes
+                             for x in jax.tree.leaves(data.arrays))
+                d2h.set_metadata(bytes=nbytes)
+            with lock:
+                self._d2h_bytes += nbytes
             for ep in self.host_eps:
                 ep_name = ep.name
-                t0 = time.perf_counter()
-                data = ep.execute(data)
-                dt = time.perf_counter() - t0
-                with self._lock:
-                    self._host_s[ep.name] = self._host_s.get(ep.name, 0.0) + dt
+                with timed_span(f"insitu.host.{ep.name}", step, lock,
+                                self._host_s, ep.name):
+                    data = ep.execute(data)
             with self._lock:
                 self._done += 1
                 self._last_out = data
@@ -219,8 +236,8 @@ class HostPipeline:
                 "submitted": subs,
                 "completed": self._done,
                 "dropped": self._dropped,
-                "wait_s": self._wait_s,
-                "backpressure_s": self._backpressure_s,
+                **self._secs,
+                "d2h_bytes": self._d2h_bytes,
                 "host_timings_s": dict(self._host_s),
                 "queue_depth_max": self._depth_max,
                 "queue_depth_mean": (self._depth_sum / subs) if subs else 0.0,
@@ -234,9 +251,33 @@ class HostPipeline:
         state only."""
         with self._lock:
             self._submitted = self._done = self._dropped = 0
-            self._wait_s = self._backpressure_s = 0.0
+            self._secs.update(dict.fromkeys(_SECS, 0.0))
+            self._d2h_bytes = 0
             self._host_s.clear()
             self._depth_max = self._depth_sum = 0
+
+
+@contextlib.contextmanager
+def timed_span(name: str, step: int, lock, counters: Dict[str, float],
+               *keys: str):
+    """``name`` as a host span (argument ``step``) in a profiler trace;
+    once the body returns, its elapsed seconds are also added, under
+    ``lock``, to ``counters[k]`` for each of ``keys``. One interval
+    feeds both the trace and ``HostPipeline.report()``."""
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(name, step=step) as span:
+        yield span
+    dt = time.perf_counter() - t0
+    with lock:
+        for k in keys:
+            counters[k] = counters.get(k, 0.0) + dt
+
+
+def span_step(step) -> int:
+    """A field's step as a span argument: the producer's host integer,
+    or -1 where it is a device scalar (reading that would wait for the
+    device)."""
+    return int(step) if isinstance(step, (int, np.integer)) else -1
 
 
 def _step_of(data) -> Any:

@@ -63,20 +63,26 @@ class SpectralSolverBase:
         # and their exchange rendezvous interleave (observed deadlock
         # on the CPU backend). A single computation per step cannot
         # interleave with itself.
-        if self.stepper == "rk4":
-            self._step_fn = jax.jit(
-                lambda s: rk4_step(self._rhs_full, s, self.dt))
-        else:
-            self._step_fn = jax.jit(
-                lambda s: ifrk4_step(self._nonlinear, s, self.dt,
-                                     self._e_half, self._e_full))
+        self._step_fn = jax.jit(self._step_scoped)
+
+    def _step_scoped(self, state):
+        """One step under the ``solver.step`` scope in traces."""
+        with jax.named_scope("solver.step"):
+            if self.stepper == "rk4":
+                return rk4_step(self._rhs_full, state, self.dt)
+            return ifrk4_step(self._nonlinear_scoped, state, self.dt,
+                              self._e_half, self._e_full)
+
+    def _nonlinear_scoped(self, state):
+        with jax.named_scope("solver.nonlinear"):
+            return self._nonlinear(state)
 
     # -- subclass hooks ------------------------------------------------------
     def _nonlinear(self, state):
         raise NotImplementedError
 
     def _rhs_full(self, state):
-        n = self._nonlinear(state)
+        n = self._nonlinear_scoped(state)
         return jax.tree_util.tree_map(
             lambda ni, lam, si: ni + lam * si, n, self._decay_dev, state)
 
@@ -100,12 +106,21 @@ class SpectralSolverBase:
         """0.5·Σ w·|ŝ|²/N² (+optional extra per-mode factor) — the
         Parseval mean-square of the real field, Hermitian-corrected."""
         b = self.basis
-        re = np.asarray(b.gather_spectral(pair[0]), np.float64)
-        im = np.asarray(b.gather_spectral(pair[1]), np.float64)
-        p = (re * re + im * im) * np.asarray(b.weights, np.float64)
-        if extra is not None:
-            p = p * np.asarray(extra, np.float64)
-        return float(np.sum(p)) * 0.5 / (b.norm * b.norm)
+        span = jax.profiler.TraceAnnotation
+        # wait for the steps in flight first, so the gather span times
+        # the copies alone
+        with span("solver.wait_device", step=self.step_count):
+            jax.block_until_ready(pair)
+        with span("solver.gather", step=self.step_count) as gather:
+            re = b.gather_spectral(pair[0])
+            im = b.gather_spectral(pair[1])
+            gather.set_metadata(bytes=re.nbytes + im.nbytes)
+            re, im = np.asarray(re, np.float64), np.asarray(im, np.float64)
+        with span("solver.parseval_sum", step=self.step_count):
+            p = (re * re + im * im) * np.asarray(b.weights, np.float64)
+            if extra is not None:
+                p = p * np.asarray(extra, np.float64)
+            return float(np.sum(p)) * 0.5 / (b.norm * b.norm)
 
     def spectrum_pair(self, pair, nbins: int = 32, *, extra=None):
         """Shell-summed E(k) of one spectral pair through the basis'
