@@ -13,7 +13,9 @@ from __future__ import annotations
 from typing import Dict, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.ckpt import checkpoint
 from repro.core.fft.spectrum import radial_spectrum_k
@@ -21,6 +23,17 @@ from repro.core.solver.spectral import SpectralBasis
 from repro.core.solver.stepper import exp_factors, ifrk4_step, rk4_step
 
 STEPPERS = ("rk4", "if_rk4")
+
+
+def _parseval_rows(re, im, weights, extra):
+    """Float32 row partials of w·|ŝ|²(·extra), summed over the last
+    (half-spectrum) axis. Elementwise products and a ``sum``, never a
+    dot: a TPU matmul at default precision runs in bf16 passes."""
+    with jax.named_scope("solver.parseval"):
+        p = (re * re + im * im) * weights
+        if extra is not None:
+            p = p * extra
+        return jnp.sum(p, axis=-1)
 
 
 class SpectralSolverBase:
@@ -64,6 +77,10 @@ class SpectralSolverBase:
         # on the CPU backend). A single computation per step cannot
         # interleave with itself.
         self._step_fn = jax.jit(self._step_scoped)
+        # the tables (weights, inv_k2) are arguments, not closed-over
+        # constants the compiler would have to embed
+        self._rows_fn = jax.jit(_parseval_rows, out_shardings=NamedSharding(
+            self.basis.mesh, PartitionSpec()))
 
     def _step_scoped(self, state):
         """One step under the ``solver.step`` scope in traces."""
@@ -97,30 +114,27 @@ class SpectralSolverBase:
             self.t = self.step_count * self.dt
 
     # -- Parseval diagnostics ------------------------------------------------
-    # Diagnostics gather the (small) spectral state to host numpy
-    # first: all processes reach the same allgather in program order
-    # and the arithmetic after it is local — identical on every
-    # process by construction, which is the agreement contract the
-    # in-situ monitoring path relies on.
+    # The products and the sums within a row (at most ``hp`` terms) run
+    # on the device in float32; only the vector of row partials comes
+    # back. It is replicated, so every process reads the same bits from
+    # its own device and finishes the same float64 sum over them in the
+    # same order: identical on every process, the agreement contract
+    # the in-situ monitoring path relies on.
     def _weighted_sum(self, pair, extra=None) -> float:
         """0.5·Σ w·|ŝ|²/N² (+optional extra per-mode factor) — the
         Parseval mean-square of the real field, Hermitian-corrected."""
         b = self.basis
         span = jax.profiler.TraceAnnotation
-        # wait for the steps in flight first, so the gather span times
-        # the copies alone
+        # wait for the steps in flight first, so the sum's span times
+        # the reduction and its copy alone
         with span("solver.wait_device", step=self.step_count):
             jax.block_until_ready(pair)
-        with span("solver.gather", step=self.step_count) as gather:
-            re = b.gather_spectral(pair[0])
-            im = b.gather_spectral(pair[1])
-            gather.set_metadata(bytes=re.nbytes + im.nbytes)
-            re, im = np.asarray(re, np.float64), np.asarray(im, np.float64)
-        with span("solver.parseval_sum", step=self.step_count):
-            p = (re * re + im * im) * np.asarray(b.weights, np.float64)
-            if extra is not None:
-                p = p * np.asarray(extra, np.float64)
-            return float(np.sum(p)) * 0.5 / (b.norm * b.norm)
+        with span("solver.parseval_sum", step=self.step_count) as psum:
+            rows = self._rows_fn(pair[0], pair[1], b.weights, extra)
+            rows = np.asarray(rows.addressable_data(0))
+            psum.set_metadata(bytes=rows.nbytes)
+            total = float(np.sum(rows, dtype=np.float64))
+            return total * 0.5 / (b.norm * b.norm)
 
     def spectrum_pair(self, pair, nbins: int = 32, *, extra=None):
         """Shell-summed E(k) of one spectral pair through the basis'

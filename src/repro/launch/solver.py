@@ -209,8 +209,8 @@ def main(argv=None):
             rep["enstrophy"] = solver.enstrophy()
         else:
             rep["scalar_variance"] = solver.scalar_variance()
-        # the diagnostics above copy the state to the host, so this
-        # interval ends after the device finished its steps
+        # the diagnostics above wait for the steps in flight, so this
+        # interval ends after the device finished them
         rep["interval_s"] = time.perf_counter() - t_interval
         reports.append(rep)
         if jax.process_index() == 0:

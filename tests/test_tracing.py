@@ -34,8 +34,8 @@ def _locations(lowered) -> str:
                                 lowered.as_text(debug_info=True)))
 
 
-def _solver(mesh):
-    s = NS2DSolver((16, 16), mesh, nu=1e-3, dt=5e-3)
+def _solver(mesh, shape=(16, 16)):
+    s = NS2DSolver(shape, mesh, nu=1e-3, dt=5e-3)
     s.init_random(seed=1)
     return s
 
@@ -97,7 +97,8 @@ def test_host_spans_carry_their_step(mesh, tmp_path):
         {"endpoint": "visualize", "array": "field",
          "out_dir": str(tmp_path / "viz")}]}, mesh=mesh, grid=grid)
     field = jnp.ones(SHAPE, jnp.float32)
-    solver = _solver(mesh)
+    # rows long enough that one partial a row is under 1% of a leaf
+    solver = _solver(mesh, shape=(16, 256))
     solver.step(1)
     solver.energy()          # compile and warm outside the trace
     trace_dir = str(tmp_path / "trace")
@@ -113,7 +114,8 @@ def test_host_spans_carry_their_step(mesh, tmp_path):
     names = {n for n, _ in spans}
     assert {"insitu.dispatch", "insitu.submit", "insitu.wait_device",
             "insitu.d2h", "insitu.host.visualize", "solver.wait_device",
-            "solver.gather", "solver.parseval_sum"} <= names
+            "solver.parseval_sum"} <= names
+    assert "solver.gather" not in names
     monitor = {n for n in names if n.startswith("solver.")}
     for name in names - monitor:
         assert sorted(a["step"] for n, a in spans if n == name) == [5, 6]
@@ -123,8 +125,9 @@ def test_host_spans_carry_their_step(mesh, tmp_path):
     # zero imaginary part and the energies
     d2h = [a["bytes"] for n, a in spans if n == "insitu.d2h"]
     assert all(b >= 2 * field.nbytes for b in d2h)
-    (gather,) = [a["bytes"] for n, a in spans if n == "solver.gather"]
-    assert gather == sum(x.nbytes for x in solver.state)
+    # the energy's row partials, not the state, came to the host
+    (copied,) = [a["bytes"] for n, a in spans if n == "solver.parseval_sum"]
+    assert 0 < copied < 0.01 * solver.state[0].nbytes
 
 
 def test_pipeline_reports_the_copy_inside_its_wait(mesh, tmp_path):
